@@ -324,6 +324,8 @@ def merge_rows(rows, path=None):
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--profile", default="test")
     ap.add_argument("--n-enc", type=int, default=40)

@@ -210,6 +210,8 @@ def _recovery_row(profile, buckets, enc_msgs, solo_cts):
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--profile", default="test",
                     help="CKKS profile; 'test' keeps the wire ratio "

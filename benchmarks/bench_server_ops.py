@@ -116,6 +116,8 @@ def run(profile: str = "tiny", reps: int = 20):
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--profile", default="tiny",
                     help="measured preset (tiny | server)")

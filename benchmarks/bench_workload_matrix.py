@@ -144,6 +144,8 @@ def run(presets=FAST_PRESETS, tenants=("alice", "bob"), n_enc: int = 20,
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--presets", default=",".join(FAST_PRESETS),
                     help="comma-separated preset names (nightly adds "

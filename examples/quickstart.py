@@ -29,6 +29,8 @@ from repro.kernels import ops as kops
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--profile", default="test",
                     help="tiny (N=2^6, smoke) | test (N=2^10, CPU-fast) | "

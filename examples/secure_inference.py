@@ -127,6 +127,8 @@ def run_encrypted(args) -> None:
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--direct", action="store_true",
                     help="call the FHEClient batched path directly instead "

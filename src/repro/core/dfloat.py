@@ -20,6 +20,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 
@@ -69,15 +70,45 @@ def quick_two_sum(a, b):
     return s, b - (s - a)
 
 
-def two_prod(a, b):
-    """Error-free a*b = p + e via Veltkamp splitting (no FMA)."""
-    p = a * b
+def _split(a):
+    """Dekker split a = hi + lo with both halves short enough that every
+    partial product is exact. f32 truncates the mantissa with a bit mask
+    (12 + 12 bits): no multiply, so no compiler can contract the split
+    into a fused multiply-add and change it. f64 keeps Veltkamp's
+    splitter (53 bits do not truncate into two 26-bit halves)."""
+    if jnp.dtype(a.dtype) == jnp.float32:
+        if isinstance(a, (np.ndarray, np.generic)):     # a literal constant
+            hi = (np.asarray(a).view(np.uint32)
+                  & np.uint32(0xFFFFF000)).view(np.float32)
+            return hi, a - hi
+        bits = jax.lax.bitcast_convert_type(a, jnp.uint32)
+        hi = jax.lax.bitcast_convert_type(bits & np.uint32(0xFFFFF000),
+                                          jnp.float32)
+        return hi, a - hi
     # numpy scalar (not jnp) so Pallas kernels see a literal, not a capture
     c = jnp.dtype(a.dtype).type(_split_const(a.dtype))
-    a_hi = c * a - (c * a - a)
-    a_lo = a - a_hi
-    b_hi = c * b - (c * b - b)
-    b_lo = b - b_hi
+    hi = c * a - (c * a - a)
+    return hi, a - hi
+
+
+def _uncontracted(p, other):
+    """The rounded product p, opaque to multiply-add contraction: a select
+    the compiler cannot fold (its arms differ, and it picks `other` only
+    for a NaN p) stands between the multiply and the add that consumes
+    it. XLA's CPU backend otherwise fuses ``e + x*y`` into one FMA, which
+    rounds once where the chip (and IEEE op-by-op evaluation) rounds
+    twice."""
+    return jnp.where(p != p, other, p)
+
+
+def two_prod(a, b):
+    """Error-free a*b = p + e via Dekker's product (no FMA). The partial
+    products are exact, so e is the unique rounding error of p however
+    the compiler schedules them. p itself is kept out of contraction:
+    ``p + e`` fused into fma(a, b, e) would round the exact product."""
+    p = _uncontracted(a * b, a)
+    a_hi, a_lo = _split(a)
+    b_hi, b_lo = _split(b)
     e = ((a_hi * b_hi - p) + a_hi * b_lo + a_lo * b_hi) + a_lo * b_lo
     return p, e
 
@@ -94,7 +125,8 @@ def df_sub(x: DF, y: DF) -> DF:
 
 def df_mul(x: DF, y: DF) -> DF:
     p, e = two_prod(x.hi, y.hi)
-    e = e + x.hi * y.lo + x.lo * y.hi
+    e = (e + _uncontracted(x.hi * y.lo, x.lo)
+         + _uncontracted(x.lo * y.hi, y.lo))
     return DF(*quick_two_sum(p, e))
 
 

@@ -227,10 +227,16 @@ def centered_to_df(sign, hi, lo, inv_scale) -> dfl.DF:
     s48 = np.float32(2.0 ** 48)
     mask = np.uint32(0xFFFF)
     s = sign * inv_scale                                 # +-2^-k, exact
-    w0 = (lo & mask).astype(f32) * s
-    w1 = (lo >> 16).astype(f32) * s16 * s
-    w2 = (hi & mask).astype(f32) * s32 * s
-    w3 = (hi >> 16).astype(f32) * s48 * s
+
+    def field(x):
+        # 16-bit fields go through int32: exact, and the chip has no
+        # direct uint32 -> float32 conversion
+        return x.astype(jnp.int32).astype(f32)
+
+    w0 = field(lo & mask) * s
+    w1 = field(lo >> 16) * s16 * s
+    w2 = field(hi & mask) * s32 * s
+    w3 = field(hi >> 16) * s48 * s
     return dfl.terms4_to_df(w3, w2, w1, w0)
 
 

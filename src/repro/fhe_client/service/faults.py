@@ -31,6 +31,32 @@ class StreamFault(RuntimeError):
         self.reason = reason
 
 
+# How the chip's compilers word a refusal inside a JaxRuntimeError: the
+# Pallas TPU backend's and XLA's own.
+COMPILE_REFUSALS = ("Mosaic failed to compile", "XLA:TPU compile")
+
+
+def is_stream_fault(exc: BaseException) -> bool:
+    """Whether a launch or read-back failure is the stream's fault, to be
+    handled by marking the stream dead and retrying on a survivor.
+
+    Only an injected ``StreamFault`` or a device runtime error qualifies.
+    A program the device refuses to lower or compile — a Pallas lowering
+    error (raised as a Python error, not a runtime one), or a runtime
+    error that carries one of the compilers' refusal prefixes
+    (``COMPILE_REFUSALS``) — is deterministic: every stream would refuse
+    the same program, so it propagates to the caller as itself and is
+    never counted as a stream death.
+    """
+    import jax
+    if isinstance(exc, StreamFault):
+        return True
+    if isinstance(exc, jax.errors.JaxRuntimeError):
+        msg = str(exc)
+        return not any(r in msg for r in COMPILE_REFUSALS)
+    return False
+
+
 class AllStreamsFailed(RuntimeError):
     """Every execution stream is dead; the service cannot make progress."""
 

@@ -101,6 +101,84 @@ class MeshError(RuntimeError):
     """Mesh-level failure (protocol violation, startup failure)."""
 
 
+# PCI identity of a TPU chip: Google's vendor id and the chips' device ids
+# (v3, v4, v5p, v5e, v6e, TPU7x) — what libtpu and JAX look for. Other
+# Google PCI devices (a gVNIC) share the vendor id, not a device id.
+GOOGLE_PCI_VENDOR = "0x1ae0"
+TPU_PCI_DEVICES = frozenset(
+    ("0x0027", "0x005e", "0x0062", "0x0063", "0x006f", "0x0076"))
+
+
+def host_tpu_chips(pci_root: str = "/sys/bus/pci/devices",
+                   dev_root: str = "/dev") -> int:
+    """TPU chips this host lets its processes open — never asked of JAX,
+    so the router process holds no chip.
+
+    The PCI bus shows whether the host has TPU chips at all; it can list
+    chips the host does not pass to this machine, so the count is that of
+    the chips' device files (``accel<n>``, or ``vfio/<n>`` on newer
+    generations), which are read only where the PCI bus shows a TPU: other
+    devices passed through VFIO are no chips."""
+    import glob
+
+    def read(path):
+        try:
+            with open(path) as f:
+                return f.read().strip()
+        except OSError:
+            return ""
+
+    if not any(read(os.path.join(dev, "vendor")) == GOOGLE_PCI_VENDOR
+               and read(os.path.join(dev, "device")) in TPU_PCI_DEVICES
+               for dev in glob.glob(os.path.join(pci_root, "*"))):
+        return 0
+    accel = glob.glob(os.path.join(dev_root, "accel[0-9]*"))
+    return len(accel) or len(glob.glob(os.path.join(dev_root, "vfio",
+                                                     "[0-9]*")))
+
+
+def one_chip_env(env: dict, chip: int) -> dict:
+    """Worker environment that gives libtpu exactly one chip of the host.
+
+    Each worker also gets its own process port, and JAX is given the TPU
+    first and the CPU (for host-side keygen) explicitly, so a worker that
+    cannot take its chip fails instead of falling back to the CPU. The
+    chips are disjoint, so the host-wide libtpu load lock, which would
+    refuse the second worker, is lifted for them — the recipe JAX's own
+    multi-process TPU tests use."""
+    env = dict(env)
+    port = 8476 + chip
+    env.update({
+        "JAX_PLATFORMS": "tpu,cpu",
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": str(port),
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    })
+    return env
+
+
+def worker_envs(env: dict, n: int, chips: int) -> list[dict]:
+    """Environments of ``n`` mesh workers started from ``env`` on a host
+    with ``chips`` TPU chips.
+
+    Where ``env`` lets JAX take the TPU (``JAX_PLATFORMS`` unset, empty
+    or naming ``tpu``) and the host has chips, each worker gets one chip
+    of its own (``one_chip_env``), and a host with fewer chips than
+    workers is refused. Otherwise — no chips, or a caller that asked for
+    a CPU mesh with ``JAX_PLATFORMS=cpu`` — every worker inherits ``env``
+    unchanged."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    if not chips or (platforms and "tpu" not in platforms.split(",")):
+        return [env] * n
+    if n > chips:
+        raise MeshError(f"{n} mesh workers need one TPU chip each; "
+                        f"this host has {chips}")
+    return [one_chip_env(env, wid) for wid in range(n)]
+
+
 class AllWorkersFailed(MeshError):
     """Every worker process is dead; the mesh cannot make progress."""
 
@@ -201,7 +279,11 @@ class MeshRouter:
     """Front-end of the multi-process service mesh.
 
     ``n_workers`` worker subprocesses are spawned on construction; each
-    connects back over localhost TCP and says HELLO. Submits mirror the
+    connects back over localhost TCP and says HELLO. On a TPU host each
+    worker is given one chip of its own (``worker_envs``), and a host
+    with fewer chips than workers is refused up front; with
+    ``JAX_PLATFORMS=cpu`` in the router's environment the workers stay on
+    the CPU. Submits mirror the
     ``ClientService`` API (``submit_encrypt``/``submit_decrypt`` with
     ``tenant``/``params`` lanes, ``flush``, ``result``); decrypt submits
     additionally accept SEEDED ciphertexts, which travel as kind-2
@@ -276,10 +358,14 @@ class MeshRouter:
     def _spawn_workers(self, n: int, faults: dict, registry_capacity: int,
                        timeout_s: float):
         import repro
+        from repro.compile_cache import CACHE_ENV, cache_dir
         env = dict(os.environ)
         src = os.path.dirname(os.path.dirname(repro.__file__))
         env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
                                    if env.get("PYTHONPATH") else "")
+        # one persistent compile cache for the router and its workers
+        env[CACHE_ENV] = cache_dir()
+        envs = worker_envs(env, n, host_tpu_chips())
         lst = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         try:
             lst.bind(("127.0.0.1", 0))
@@ -290,7 +376,8 @@ class MeshRouter:
             for wid in range(n):
                 procs[wid] = subprocess.Popen(
                     self._worker_cmd(wid, port, registry_capacity,
-                                     faults.get(wid)), env=env)
+                                     faults.get(wid)),
+                    env=envs[wid])
             for _ in range(n):
                 try:
                     conn, _addr = lst.accept()

@@ -37,7 +37,7 @@ import threading
 
 from repro.core import scheduler as policy
 from repro.fhe_client.service.batcher import now, oldest_age
-from repro.fhe_client.service.faults import AllStreamsFailed, RequestFailed
+from repro.fhe_client.service.faults import RequestFailed
 
 
 class JetThread(threading.Thread):
@@ -130,6 +130,14 @@ class DispatchLoop:
     def _record_crash(self, exc: BaseException):
         svc = self.service
         svc.events.record("loop_error", detail=repr(exc))
+        # a dead completion thread runs no more launched jobs: fail them
+        while threading.current_thread() is self._completion:
+            try:
+                item = self._completion_q.get_nowait()
+            except queue.Empty:
+                break
+            if item is not _SENTINEL:
+                svc._fail(item[1], item[0].attempt, exc)
         with svc._cond:
             self._fail_queued_locked(exc)
             svc._cond.notify_all()    # wake result()/submit waiters
@@ -217,13 +225,7 @@ class DispatchLoop:
                         detail=f"{kind} bucket {job.bucket} "
                                f"({job.n_real} real)")
             if enc_jobs or dec_jobs:
-                with svc._sched_lock:
-                    launched, undispatched = svc.scheduler.dispatch(
-                        enc_jobs, dec_jobs)
-                for job in undispatched:
-                    svc._fail(job, 0, AllStreamsFailed(
-                        f"no alive stream for job rids={job.rids}"))
-                for item in launched:
+                for item in svc._dispatch(enc_jobs, dec_jobs):
                     self._completion_q.put(item)
         self._completion_q.put(_SENTINEL)
 

@@ -33,12 +33,13 @@ from collections import deque
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import scheduler as policy
 from repro.distributed import sharding as shd
 from repro.fhe_client.service.batcher import DecJob, EncJob, now
-from repro.fhe_client.service.faults import AllStreamsFailed, EventLog
+from repro.fhe_client.service.faults import (
+    AllStreamsFailed, EventLog, is_stream_fault,
+)
 from repro.kernels import ops as kops
 
 
@@ -114,10 +115,10 @@ class StreamExecutor:
             *ops, n0 = args
             return impl(*ops, kops.shard_nonce_base(n0, ops[0].shape[0]))
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P("batch"),) * n_ops + (P(),),
-            out_specs=P("batch"), check_rep=False))
+            out_specs=P("batch"), check_vma=False))
 
     def _sharded_dec_core(self, client):
         impl = client.decrypt_impl
@@ -125,10 +126,10 @@ class StreamExecutor:
         def local(c0, c1, scale):
             return impl(c0, c1, scale)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P("batch"), P("batch"), P("batch")),
-            out_specs=P("batch"), check_rep=False))
+            out_specs=P("batch"), check_vma=False))
 
     # --- placement ----------------------------------------------------------
 
@@ -244,7 +245,8 @@ class DualStreamScheduler:
         ``plan_rounds(n_enc, n_dec, n_alive)`` and ``undispatched`` is
         empty. A launch that raises marks its stream dead and re-queues
         the job at the FRONT of its queue (same job, same nonce lease) for
-        the surviving streams."""
+        the surviving streams. A lowering or compile refusal is not a
+        stream fault (``faults.is_stream_fault``) and propagates."""
         enc_q, dec_q = deque(enc_jobs), deque(dec_jobs)
         launched = []
         while enc_q or dec_q:
@@ -259,7 +261,9 @@ class DualStreamScheduler:
                 job = q.popleft()
                 try:
                     out = self.launch_job(stream, job)
-                except Exception as e:  # noqa: BLE001 — any launch failure
+                except Exception as e:  # noqa: BLE001 — classified below
+                    if not is_stream_fault(e):
+                        raise
                     q.appendleft(job)
                     self.events.record(
                         "requeue", stream=stream, round=self._round,
@@ -296,7 +300,9 @@ class DualStreamScheduler:
             stream = alive[0]
             try:
                 out = self.launch_job(stream, job, attempt=attempt)
-            except Exception as e:  # noqa: BLE001
+            except Exception as e:  # noqa: BLE001 — classified below
+                if not is_stream_fault(e):
+                    raise
                 self.events.record(
                     "requeue", stream=stream, round=self._round,
                     rids=job.rids, attempt=attempt,

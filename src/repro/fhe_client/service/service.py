@@ -58,7 +58,7 @@ from repro.fhe_client.service.batcher import (CoalescingBatcher,
                                               DEFAULT_BUCKETS, EncJob,
                                               Request, now, oldest_age)
 from repro.fhe_client.service.faults import (AllStreamsFailed, EventLog,
-                                             RequestFailed)
+                                             RequestFailed, is_stream_fault)
 from repro.fhe_client.service.scheduler import DualStreamScheduler
 from repro.fhe_client.tenancy import (KeyContextRegistry,
                                       params_fingerprint)
@@ -557,7 +557,10 @@ class ClientService:
             try:
                 self.scheduler.check_materialize(rec, job)
                 jax.block_until_ready(out)
-            except Exception as e:  # noqa: BLE001 — any materialize failure
+            except Exception as e:  # noqa: BLE001 — classified below
+                if not is_stream_fault(e):
+                    self._fail(job, attempt, e)
+                    raise
                 with self._sched_lock:
                     self.scheduler.mark_failed(rec.stream, detail=repr(e))
                     self._sync_monitor_locked()
@@ -576,6 +579,9 @@ class ClientService:
                     except AllStreamsFailed as dead:
                         self._fail(job, attempt, dead)
                         return
+                    except Exception as refused:  # noqa: BLE001
+                        self._fail(job, attempt, refused)
+                        raise
                 continue
             break
         dt = now() - t0
@@ -637,16 +643,36 @@ class ClientService:
         self._prepare_lanes(queued_keys)
         with self._cond:
             enc_jobs, dec_jobs = self._coalesce_locked()
-        with self._sched_lock:
-            launched, undispatched = self.scheduler.dispatch(enc_jobs,
-                                                             dec_jobs)
         done0 = self._completed_total
+        launched = self._dispatch(enc_jobs, dec_jobs)
+        for i, (rec, job, out) in enumerate(launched):
+            try:
+                self._run_job(rec, job, out)
+            except Exception as e:  # noqa: BLE001 — _run_job failed its job
+                for _rec, rest, _out in launched[i + 1:]:
+                    self._fail(rest, 0, e)
+                raise
+        return self._completed_total - done0
+
+    def _dispatch(self, enc_jobs, dec_jobs):
+        """Launch coalesced jobs through the scheduler; returns the
+        launched ``[(record, job, out)]``. A job no stream could take
+        fails with ``AllStreamsFailed``. Anything the scheduler raises (a
+        device's compile refusal) fails every job of the call, launched
+        or not — their requests are already out of the queues — and
+        propagates."""
+        try:
+            with self._sched_lock:
+                launched, undispatched = self.scheduler.dispatch(enc_jobs,
+                                                                 dec_jobs)
+        except Exception as e:  # noqa: BLE001 — fail, then propagate
+            for job in list(enc_jobs) + list(dec_jobs):
+                self._fail(job, 0, e)
+            raise
         for job in undispatched:      # every stream died before launch
             self._fail(job, 0, AllStreamsFailed(
                 f"no alive stream for job rids={job.rids}"))
-        for rec, job, out in launched:
-            self._run_job(rec, job, out)
-        return self._completed_total - done0
+        return launched
 
     # --- result retrieval ----------------------------------------------------
 

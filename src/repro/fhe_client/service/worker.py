@@ -229,6 +229,14 @@ def main(argv=None):
     ap.add_argument("--die-after-submits", type=int, default=None)
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    if os.environ.get("TPU_VISIBLE_CHIPS") is not None:
+        import jax
+        devs = jax.devices()
+        if len(devs) != 1 or devs[0].platform != "tpu":
+            raise SystemExit(f"worker {args.worker_id}: expected one TPU "
+                             f"chip, JAX reports {devs}")
     params = CKKSParams(logn=args.logn, n_limbs=args.n_limbs,
                         decrypt_limbs=args.decrypt_limbs,
                         delta_bits=args.delta_bits, p_bw=args.p_bw,

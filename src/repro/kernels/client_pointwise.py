@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import modmul, prng, rns
+from repro.core import modmul, prng
 from repro.core.context import CKKSContext
 from repro.core.encryptor import (
     STREAM_ENC_E0, STREAM_ENC_E1, STREAM_ENC_V,
@@ -52,18 +52,29 @@ from repro.kernels import common
 # ---------------------------------------------------------------------------
 
 
-def _random_u32_k(seed128: int, stream, n: int, word: int, rows: int = 1):
-    """(rows, n) uint32 Philox draw; `stream` may be a traced scalar (one
-    stream for every row) or a traced (rows, 1) column (one stream per row,
-    the batch-blocked kernels).
+def _elem_index(shape):
+    """uint32 coefficient index of every element of a (rows, n) block or
+    a tiled (rows, R, C) block (element (r, c) of a tile holds r*C + c)."""
+    if len(shape) == 2:
+        return jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
+    return (jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
+            * np.uint32(shape[2])
+            + jax.lax.broadcasted_iota(jnp.uint32, shape, 2))
+
+
+def _random_u32_k(seed128: int, stream, shape, word: int):
+    """uint32 Philox draw over a (rows, n) or tiled (rows, R, C) block;
+    `stream` may be a traced scalar (one stream for every row) or a
+    traced column broadcasting over the row axis (one stream per row, the
+    batch-blocked kernels).
 
     Bit-identical per row to ``prng.random_u32`` (same counter layout), but
-    built from numpy-literal key material and a 2D iota so Pallas captures
+    built from numpy-literal key material and an iota so Pallas captures
     nothing.
     """
     parts = [np.uint32((seed128 >> (32 * i)) & 0xFFFFFFFF) for i in range(4)]
     key = (parts[0], parts[1])
-    idx = jax.lax.broadcasted_iota(jnp.uint32, (rows, n), 1)
+    idx = _elem_index(shape)
     z = jnp.zeros_like(idx)
     ctr = (
         idx,
@@ -74,16 +85,16 @@ def _random_u32_k(seed128: int, stream, n: int, word: int, rows: int = 1):
     return prng.philox_4x32(ctr, key)[0]
 
 
-def _zo_k(seed128: int, stream, n: int, rows: int = 1):
-    u = _random_u32_k(seed128, stream, n, 0, rows)
+def _zo_k(seed128: int, stream, shape):
+    u = _random_u32_k(seed128, stream, shape, 0)
     return jnp.where(
         u < np.uint32(1 << 30), jnp.int32(1),
         jnp.where(u < np.uint32(1 << 31), jnp.int32(-1), jnp.int32(0)))
 
 
-def _cbd_k(seed128: int, stream, n: int, rows: int = 1):
-    a = _random_u32_k(seed128, stream, n, 0, rows)
-    b = _random_u32_k(seed128, stream, n, 1, rows)
+def _cbd_k(seed128: int, stream, shape):
+    a = _random_u32_k(seed128, stream, shape, 0)
+    b = _random_u32_k(seed128, stream, shape, 1)
     return (prng._popcount21(a).astype(jnp.int32)
             - prng._popcount21(b).astype(jnp.int32))
 
@@ -108,9 +119,9 @@ def _encrypt_kernel(pt_ref, b_ref, a_ref, c0_ref, c1_ref, *,
     s0 = np.uint32(STREAM_ENC_E0) + np.uint32(16) * nonce
     s1 = np.uint32(STREAM_ENC_E1) + np.uint32(16) * nonce
 
-    v = _to_residue_k(_zo_k(seed, sv, n), q)
-    e0 = _to_residue_k(_cbd_k(seed, s0, n), q)
-    e1 = _to_residue_k(_cbd_k(seed, s1, n), q)
+    v = _to_residue_k(_zo_k(seed, sv, (1, n)), q)
+    e0 = _to_residue_k(_cbd_k(seed, s0, (1, n)), q)
+    e1 = _to_residue_k(_cbd_k(seed, s1, (1, n)), q)
 
     v_h = common.ntt_stages(v, pc)
     e0_h = common.ntt_stages(e0, pc)
@@ -187,38 +198,20 @@ def decrypt_limb(c0_l, c1_l, s_mont_l, ctx: CKKSContext, limb: int,
 # for ciphertext b*bb + r — bit-identical outputs.
 
 
-def sample_vee_k(seed: int, nonce, n: int, rows: int):
-    """In-kernel (v, e0, e1) encryption randomness for `rows` batch rows.
+def sample_vee_k(seed: int, nonce, shape):
+    """In-kernel (v, e0, e1) encryption randomness for a block of batch
+    rows, (rows, n) or tiled (rows, R, C).
 
-    nonce: traced (rows, 1) uint32 column (base + per-row offset). Returns
-    SIGNED int32 draws — limb-independent, exactly the streams the host
-    reference samples — so one sampling pass feeds every limb's
-    ``encrypt_limb_stage`` (the residue cast is per-limb).
+    nonce: traced uint32 column (base + per-row offset) broadcasting over
+    the row axis. Returns SIGNED int32 draws — limb-independent, exactly
+    the streams the host reference samples — so one sampling pass feeds
+    every limb (the residue cast is per-limb).
     """
-    sv = np.uint32(STREAM_ENC_V) + np.uint32(16) * nonce     # (rows, 1)
+    sv = np.uint32(STREAM_ENC_V) + np.uint32(16) * nonce
     s0 = np.uint32(STREAM_ENC_E0) + np.uint32(16) * nonce
     s1 = np.uint32(STREAM_ENC_E1) + np.uint32(16) * nonce
-    return (_zo_k(seed, sv, n, rows), _cbd_k(seed, s0, n, rows),
-            _cbd_k(seed, s1, n, rows))
-
-
-def rns_digit_stage(digits, c_ref, kc: common.StackedKernelConsts,
-                    limb: int, c22_mont: int, c44_mont: int):
-    """df32-datapath per-limb RNS stage: exact balanced base-2^22 digits of
-    the Delta-scaled coefficients -> this limb's uint32 residues.
-
-    digits: the three int32 (rows, N) arrays from
-    ``encoder.delta_scale_digits``; (q, -q^-1) are traced reads from the
-    stacked-constants ref at row `limb`; the Montgomery-form radix
-    constants are static Python ints (the streaming megakernel unrolls the
-    limb loop, so per-limb radix scalars stay closure constants like the
-    seed/delta). Exact — bit-identical to the f64 fmod stage
-    (``rns.to_rns_limb_t``) on the same integers.
-    """
-    d0, d1, d2 = digits
-    return rns.digits_to_residue(
-        d0, d1, d2, c_ref[limb, common.OFF_Q], c_ref[limb, common.OFF_QINV],
-        np.uint32(c22_mont), np.uint32(c44_mont))
+    return (_zo_k(seed, sv, shape), _cbd_k(seed, s0, shape),
+            _cbd_k(seed, s1, shape))
 
 
 def encrypt_limb_stage(vee, pt_l, b_l, a_l, c_ref,
@@ -229,8 +222,8 @@ def encrypt_limb_stage(vee, pt_l, b_l, a_l, c_ref,
     vee: signed int32 (rows, N) draws from ``sample_vee_k``; pt_l/b_l/a_l:
     this limb's NTT-domain plaintext block and Montgomery-form pk rows;
     c_ref: the stacked-constants ref, indexed at row `limb` (0 for the
-    limb-folded kernels whose block is one row; l for the megakernel which
-    holds the whole table). Returns (c0_l, c1_l) uint32 (rows, N).
+    limb-folded kernels whose block is one row). Returns (c0_l, c1_l)
+    uint32 (rows, N).
     """
     q = c_ref[limb, common.OFF_Q]
     qinv = c_ref[limb, common.OFF_QINV]
@@ -253,7 +246,8 @@ def encrypt_limb_stage(vee, pt_l, b_l, a_l, c_ref,
 def decrypt_limb_stage(c0_l, c1_l, s_l, c_ref,
                        kc: common.StackedKernelConsts, limb: int = 0):
     """One limb of the streaming decrypt datapath: pointwise + INTT ->
-    coefficient-domain residues (rows, N)."""
+    coefficient-domain residues, (rows, N) or tiled (rows, R, C). `limb`
+    indexes c_ref (static, or the megakernel's grid index)."""
     q = c_ref[limb, common.OFF_Q]
     qinv = c_ref[limb, common.OFF_QINV]
     c1s = modmul.mulmod_montgomery_limb_t(c1_l, s_l, q, qinv)
@@ -269,7 +263,7 @@ def _encrypt_kernel_folded(c_ref, nz_ref, pt_ref, b_ref, a_ref,
     nonce = (nz_ref[0, 0]
              + pl.program_id(1).astype(jnp.uint32) * np.uint32(rows)
              + jax.lax.broadcasted_iota(jnp.uint32, (rows, 1), 0))
-    vee = sample_vee_k(seed, nonce, n, rows)
+    vee = sample_vee_k(seed, nonce, (rows, n))
     c0_ref[:, 0, :], c1_ref[:, 0, :] = encrypt_limb_stage(
         vee, pt_ref[:, 0, :], b_ref[...], a_ref[...], c_ref, kc)
 

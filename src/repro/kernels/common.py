@@ -3,9 +3,10 @@
 Per-prime constants come in two flavours. In the per-limb kernels everything
 is *static* (baked into the kernel closure): modulus, shift-add k-terms,
 Montgomery constants, and the OTF twiddle-generator seeds. In the
-limb-folded kernels (grid = (L, ...)) the same scalars are stacked into one
-(L, K) uint32 table (``stacked_kernel_consts``) and read per grid step at
-static column offsets. Both mirror the ASIC, where these live in registers /
+limb-folded kernels and the client megakernels the same scalars are
+stacked into one (L, K) uint32 table (``stacked_kernel_consts``) in SMEM,
+read by limb row (the grid index) at column offsets the stage loops
+compute from the stage index. Both mirror the ASIC, where these live in registers /
 a 27 KB seed SRAM — the TPU analogue is compile-time constants or an SMEM
 seed table + VMEM-regenerated vectors, never HBM traffic.
 
@@ -80,10 +81,9 @@ def check_datapath(datapath: str) -> str:
 
 
 def stacked_digit_consts(q_list) -> tuple:
-    """Static per-limb Montgomery-form radix constants ((c22, c44), ...)
-    for the df32 RNS digit reduction — the seed-table analogue for the
-    digit stage (the megakernel unrolls limbs, so these stay Python ints;
-    the broadcasted staged pass stacks them into (L, 1, ..) arrays)."""
+    """Per-limb Montgomery-form radix constants ((c22, c44), ...) for the
+    df32 RNS digit reduction — the seed-table analogue for the digit stage
+    (the megakernel reads them from an (L, 2) SMEM table by limb)."""
     from repro.core import rns
     return tuple(rns.digit_consts(int(q)) for q in q_list)
 
@@ -105,12 +105,6 @@ def row_block_spec(block_rows: int, n: int) -> pl.BlockSpec:
     """(block_rows, N) VMEM block indexed by the rows grid axis."""
     return pl.BlockSpec((block_rows, n), lambda i: (i, 0),
                         memory_space=pltpu.VMEM)
-
-
-def table_block_spec(k: int, n: int) -> pl.BlockSpec:
-    """Whole (k, n) VMEM-resident table, identical at every grid step
-    (the df32 kernel's packed twiddle planes)."""
-    return pl.BlockSpec((k, n), lambda i: (0, 0), memory_space=pltpu.VMEM)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -200,8 +194,9 @@ def plan_consts(plan: NTTPlan) -> PlanConsts:
 # ---------------------------------------------------------------------------
 # Folding the limb loop into the Pallas grid means per-limb constants can no
 # longer be Python-closure scalars: they arrive as one (L, K) uint32 array,
-# block-indexed by the limb grid axis, and the kernel reads each scalar at a
-# *static* column offset. Layout per limb row:
+# read by limb row, each scalar at a column offset that depends only on the
+# stage (``_fwd_off``/``_inv_off`` compute it from a traced stage index).
+# Layout per limb row:
 #
 #   [0] q   [1] -q^{-1} mod 2^32   [2] N^{-1} (Montgomery form)
 #   then per forward stage s = 0..logn-1:  base_s, f_0..f_{s-1}
@@ -372,78 +367,211 @@ def _s(m: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Traced-constant variants: same stage loops, per-limb scalars read from the
-# stacked-constants ref at static offsets (limb-folded grid kernels)
+# Traced-constant variants: per-limb scalars read from the stacked-constants
+# ref (limb-folded grid kernels and the streaming megakernels)
 # ---------------------------------------------------------------------------
 # REDC with traced (q, -q^-1) uses the general 16-bit-limb multiply path
 # (modmul.mulmod_montgomery_limb_t) because shift-add k-term exponents are
 # structurally per-prime and cannot be traced; outputs are bit-identical
 # (see the modmul docstring), so the folded kernels match the per-limb
 # shift-add kernels word-for-word.
+#
+# Tiled layout. On the chip a length-n vector lives in VMEM as (8, 128)
+# tiles, so the traced stage loops work on a 2-D view (R, C) of every
+# polynomial (``tile_layout``): C = min(N/2, 128) lanes, R = N / C rows,
+# element i at (i // C, i % C). A butterfly stage with pair stride t then
+# never reshapes: its partner is one ``pltpu.roll`` away, along the lanes
+# for t < C and along the rows for t >= C, and a select on bit t of the
+# index picks the butterfly half. Every element computes its own output,
+# so each product is formed twice; the values are the ones the reshape
+# formulation computes, word for word.
+
+LANES = 128
+
+def tile_layout(n: int) -> tuple[int, int]:
+    """(R, C) view of a length-n ring polynomial: C = min(n/2, 128) lanes.
+    The slot vectors (length n/2) of the same ring share C, so the
+    re ++ im coefficient concatenation is a row-axis concat."""
+    c = min(max(n // 2, 1), LANES)
+    return n // c, c
 
 
-def gen_twiddles_t(c_ref, off: int, nfac: int, q, qinv_neg,
-                   row: int = 0) -> jnp.ndarray:
-    """Traced OTF twiddle doubling: base/factors read from c_ref columns
-    [off, off+nfac] of limb row `row`, q/qinv_neg traced scalars. Returns
-    (2^nfac,) uint32. The limb-folded kernels see a one-row block (row=0);
-    the streaming megakernel holds the whole (L, K) table and indexes the
-    limb it is processing."""
-    zero = jax.lax.broadcasted_iota(jnp.uint32, (1,), 0)
-    a = zero + c_ref[row, off]
-    for j in range(nfac):
-        prod = modmul.mulmod_montgomery_limb_t(
-            a, c_ref[row, off + 1 + j], q, qinv_neg)
-        a = jnp.concatenate([a, prod])
-    return a
+def butterfly_pairs(x, shift, axis: int):
+    """Split a tiled (..., R, C) array into the (u, v, upper) views of the
+    butterfly stage whose pair stride is `shift` along `axis` (the lane
+    axis for strides < C, the row axis in units of rows otherwise): u/v
+    are the lower/upper pair member seen from every element, upper is
+    True where the element is the upper member. `shift` may be traced."""
+    size = x.shape[axis]
+    shape = [1] * x.ndim
+    shape[axis] = size
+    idx = jax.lax.broadcasted_iota(jnp.int32, tuple(shape), axis)
+    upper = (idx & shift) != 0
+    partner = jnp.where(upper, pltpu.roll(x, shift, axis),
+                        pltpu.roll(x, size - shift, axis))
+    return (jnp.where(upper, partner, x), jnp.where(upper, x, partner),
+            upper)
+
+
+def stage_loops(x, n_stages: int, first_row_stages: bool, stage):
+    """Run ``stage(s, x, axis, shift)`` for s in [0, n_stages) as two
+    ``fori_loop``s over a tiled (..., R, C) array (or pytree of them): the stages whose pair
+    stride crosses rows, then those inside a row (or the other way round).
+    Strides halve from R*C/2 down to 1 when `first_row_stages`, and double
+    from 1 up otherwise. A loop keeps the traced body one stage long (the
+    chip compiles it once) and makes XLA's CPU backend materialize every
+    stage in interpret mode instead of fusing the whole pipeline into one
+    recomputing expression."""
+    leaf = jax.tree_util.tree_leaves(x)[0]
+    n_rows, n_cols = leaf.shape[-2:]
+    row_axis, lane_axis = leaf.ndim - 2, leaf.ndim - 1
+    log_r = n_rows.bit_length() - 1
+    log_c = n_cols.bit_length() - 1
+    assert log_r + log_c == n_stages
+    one = jnp.int32(1)
+
+    def loop(x, lo, hi, axis, shift_of):
+        if hi <= lo:
+            return x
+        # int32 bounds: the index must not widen to int64 under x64
+        return jax.lax.fori_loop(
+            jnp.int32(lo), jnp.int32(hi),
+            lambda s, y: stage(s, y, axis, shift_of(s)), x)
+
+    if first_row_stages:
+        x = loop(x, 0, log_r, row_axis,
+                 lambda s: jnp.right_shift(jnp.int32(n_rows), s + one))
+        return loop(x, log_r, n_stages, lane_axis,
+                    lambda s: jnp.right_shift(jnp.int32(n_cols),
+                                              s - log_r + one))
+    x = loop(x, 0, log_c, lane_axis, lambda s: jnp.left_shift(one, s))
+    return loop(x, log_c, n_stages, row_axis,
+                lambda s: jnp.left_shift(one, s - log_c))
+
+
+def _mont_one(q):
+    """R mod q (the Montgomery form of 1) from a traced q in (2^30, 2^31):
+    2^32 - q < 3q, so two conditional subtractions reduce it."""
+    one = jnp.zeros((1, 1), jnp.uint32) - q
+    for _ in range(2):
+        one = jnp.where(one >= q, one - q, one)
+    return one
+
+
+def expand_twiddles_t(c_ref, row, off, bit0, shape: tuple[int, int],
+                      q, qinv_neg):
+    """OTF twiddles of one stage, expanded to the tiled (R, C) layout.
+
+    The doubling A_{k+1} = [A_k, A_k * f_k] gives element j of a stage's
+    twiddle vector as base * prod_{bit k of j set} f_k; in the expanded
+    view element i reads j = i >> bit0. The lane bits of i build a (1, C)
+    factor and the row bits an (R, 1) factor, each by select-multiplies
+    with the seed scalars at column `off` of c_ref row `row`, and one
+    Montgomery product joins them. `row`, `off` and `bit0` may be traced
+    (the stage loops); only the seeds are ever read — the paper's unified
+    OTF TF Gen, in tile shape."""
+    n_rows, n_cols = shape
+    lane_bits = n_cols.bit_length() - 1
+    logn = lane_bits + n_rows.bit_length() - 1
+    lane_i = jax.lax.broadcasted_iota(jnp.int32, (1, n_cols), 1)
+    row_i = jax.lax.broadcasted_iota(jnp.int32, (n_rows, 1), 0)
+    lane_v = jnp.zeros((1, n_cols), jnp.uint32) + c_ref[row, off]
+    row_v = jnp.zeros((n_rows, 1), jnp.uint32) + _mont_one(q)
+    for b in range(logn):
+        k = b - bit0                      # factor index; < 0: bit unused
+        f = c_ref[row, off + 1 + jnp.maximum(k, 0)]
+        if b < lane_bits:
+            use = (((lane_i >> b) & 1) != 0) & (k >= 0)
+            lane_v = jnp.where(
+                use, modmul.mulmod_montgomery_limb_t(lane_v, f, q, qinv_neg),
+                lane_v)
+        else:
+            use = (((row_i >> (b - lane_bits)) & 1) != 0) & (k >= 0)
+            row_v = jnp.where(
+                use, modmul.mulmod_montgomery_limb_t(row_v, f, q, qinv_neg),
+                row_v)
+    return modmul.mulmod_montgomery_limb_t(
+        jnp.broadcast_to(lane_v, shape), jnp.broadcast_to(row_v, shape),
+        q, qinv_neg)
+
+
+def _fwd_off(s):
+    """Column of forward stage s in the stacked table (traced s)."""
+    return _OFF_STAGES + ((s * (s + 1)) >> 1)
+
+
+def _inv_off(st, logn: int):
+    """Column of inverse stage st in the stacked table (traced st)."""
+    return (_OFF_STAGES + logn * (logn + 1) // 2 + st * logn
+            - ((st * (st - 1)) >> 1))
+
+
+def ntt_tiled_t(x: jnp.ndarray, c_ref, kc: StackedKernelConsts, q, qinv_neg,
+                row=0) -> jnp.ndarray:
+    """Forward negacyclic NTT (merged-psi CT DIT, in-order in ->
+    bit-reversed out) of tiled (rows, R, C) uint32 polynomials, per-limb
+    scalars from c_ref row `row` (a static int or a traced grid index).
+    Stage s has pair stride N >> (s+1) and its twiddle index starts at
+    bit log2(N) - s."""
+    shape = x.shape[-2:]
+    assert kc.fwd_off == tuple(_fwd_off(s) for s in range(kc.logn))
+
+    def stage(s, x, axis, shift):
+        tw = expand_twiddles_t(c_ref, row, _fwd_off(s), kc.logn - s, shape,
+                               q, qinv_neg)
+        u, v, upper = butterfly_pairs(x, shift, axis)
+        vw = modmul.mulmod_montgomery_limb_t(v, tw, q, qinv_neg)
+        return jnp.where(upper, modmul.submod(u, vw, q),
+                         modmul.addmod(u, vw, q))
+
+    return stage_loops(x, kc.logn, True, stage)
+
+
+def intt_tiled_t(x: jnp.ndarray, c_ref, kc: StackedKernelConsts, q, qinv_neg,
+                 row=0) -> jnp.ndarray:
+    """Inverse negacyclic NTT (GS DIF, bit-reversed in -> in-order out) of
+    tiled (rows, R, C) polynomials, N^-1 folded in at the end. Stage st
+    has pair stride 2^st and its twiddle index starts at bit st + 1."""
+    shape = x.shape[-2:]
+    assert kc.inv_off == tuple(_inv_off(s, kc.logn) for s in range(kc.logn))
+
+    def stage(st, x, axis, shift):
+        tw = expand_twiddles_t(c_ref, row, _inv_off(st, kc.logn), st + 1,
+                               shape, q, qinv_neg)
+        u, v, upper = butterfly_pairs(x, shift, axis)
+        odd = modmul.mulmod_montgomery_limb_t(modmul.submod(u, v, q), tw, q,
+                                              qinv_neg)
+        return jnp.where(upper, odd, modmul.addmod(u, v, q))
+
+    x = stage_loops(x, kc.logn, False, stage)
+    return modmul.mulmod_montgomery_limb_t(x, c_ref[row, OFF_NINV], q,
+                                           qinv_neg)
+
+
+def _on_tiles(f, x, n: int):
+    """Run a tiled stage loop on tiled (rows, R, C) polynomials as they
+    are, or on (rows, N) ones (the staged and server kernels' block
+    layout) through the tiled view."""
+    if x.ndim == 3:
+        return f(x)
+    rows = x.shape[0]
+    return f(x.reshape((rows,) + tile_layout(n))).reshape(rows, n)
 
 
 def ntt_stages_t(x: jnp.ndarray, c_ref, kc: StackedKernelConsts,
-                 q, qinv_neg, row: int = 0) -> jnp.ndarray:
-    """Forward negacyclic NTT on (rows, N) uint32 with traced per-limb
-    constants. Same butterfly schedule as ``ntt_stages``."""
-    n = kc.n
-    rows = x.shape[0]
-    m, t = 1, n
-    while m < n:
-        t //= 2
-        s = _s(m)
-        tw = gen_twiddles_t(c_ref, kc.fwd_off[s], kc.fwd_nfac(s), q, qinv_neg,
-                            row)
-        x = x.reshape(rows, m, 2, t)
-        u = x[:, :, 0, :]
-        v = modmul.mulmod_montgomery_limb_t(
-            x[:, :, 1, :], tw[None, :, None], q, qinv_neg)
-        x = jnp.stack(
-            [modmul.addmod(u, v, q), modmul.submod(u, v, q)], axis=2
-        ).reshape(rows, n)
-        m *= 2
-    return x
+                 q, qinv_neg, row=0) -> jnp.ndarray:
+    """Forward negacyclic NTT on (rows, N) or tiled (rows, R, C) uint32
+    with traced per-limb constants (``ntt_tiled_t``)."""
+    return _on_tiles(
+        lambda t: ntt_tiled_t(t, c_ref, kc, q, qinv_neg, row), x, kc.n)
 
 
 def intt_stages_t(x: jnp.ndarray, c_ref, kc: StackedKernelConsts,
-                  q, qinv_neg, row: int = 0) -> jnp.ndarray:
-    """Inverse negacyclic NTT on (rows, N) with traced per-limb constants,
-    N^-1 (read from the consts row) folded in at the end."""
-    n = kc.n
-    rows = x.shape[0]
-    h, t = n // 2, 1
-    st = 0
-    while h >= 1:
-        tw = gen_twiddles_t(c_ref, kc.inv_off[st], kc.inv_nfac(st),
-                            q, qinv_neg, row)
-        x = x.reshape(rows, h, 2, t)
-        u, v = x[:, :, 0, :], x[:, :, 1, :]
-        even = modmul.addmod(u, v, q)
-        odd = modmul.mulmod_montgomery_limb_t(
-            modmul.submod(u, v, q), tw[None, :, None], q, qinv_neg)
-        x = jnp.concatenate([even, odd], axis=-1).reshape(rows, h * 2 * t)
-        t *= 2
-        h //= 2
-        st += 1
-    x = x.reshape(rows, n)
-    return modmul.mulmod_montgomery_limb_t(x, c_ref[row, OFF_NINV], q,
-                                           qinv_neg)
+                  q, qinv_neg, row=0) -> jnp.ndarray:
+    """Inverse negacyclic NTT on (rows, N) or tiled (rows, R, C) with
+    traced per-limb constants, N^-1 folded in (``intt_tiled_t``)."""
+    return _on_tiles(
+        lambda t: intt_tiled_t(t, c_ref, kc, q, qinv_neg, row), x, kc.n)
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,6 @@ from __future__ import annotations
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import fft as fftmod
@@ -183,7 +182,7 @@ def decrypt_fused(c0, c1, s_mont, ctx: CKKSContext, n_limbs: int = 2,
 # Each shard runs the SAME limb-folded kernel on its slice of the batch
 # axis (one pallas_call per device — each device is an RSC-equivalent
 # stream), so a b-device mesh issues b concurrent launches for one batch.
-# ``check_rep=False``: shard_map has no replication rule for pallas_call;
+# ``check_vma=False``: shard_map has no replication rule for pallas_call;
 # every output is batch-sharded anyway. Nonce bases are offset per shard so
 # row r of the batch always encrypts under ``nonce0 + r`` — bit-identical
 # to the single-device launch.
@@ -223,10 +222,10 @@ def encrypt_fused_sharded(pt_data, pk_b_mont, pk_a_mont, ctx: CKKSContext,
                              nonce0=shard_nonce_base(n0, shard_b),
                              interpret=interpret)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("batch", None, None), P(None, None), P(None, None), P()),
-        out_specs=P("batch", None, None), check_rep=False,
+        out_specs=P("batch", None, None), check_vma=False,
     )(pt_data, pk_b_mont, pk_a_mont, jnp.uint32(nonce0))
 
 
@@ -240,11 +239,11 @@ def decrypt_fused_sharded(c0, c1, s_mont, ctx: CKKSContext, mesh,
         return decrypt_fused(c0_l, c1_l, s, ctx, n_limbs=n_limbs,
                              interpret=interpret)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("batch", None, None), P("batch", None, None),
                   P(None, None)),
-        out_specs=P("batch", None, None), check_rep=False,
+        out_specs=P("batch", None, None), check_vma=False,
     )(c0, c1, s_mont)
 
 
@@ -255,7 +254,6 @@ def decrypt_fused_sharded(c0, c1, s_mont, ctx: CKKSContext, mesh,
 
 def encode_encrypt_stream(planes, pk_b_mont, pk_a_mont, ctx: CKKSContext,
                           seed: int | None = None, nonce0=0,
-                          batch_block: int | None = None,
                           interpret: bool | None = None,
                           datapath: str = "f64"):
     """df32 slot planes -> (c0, c1) ciphertext stacks, ONE pallas_call:
@@ -267,11 +265,10 @@ def encode_encrypt_stream(planes, pk_b_mont, pk_a_mont, ctx: CKKSContext,
     seed = ctx.params.seed if seed is None else seed
     return client_stream.encode_encrypt_stream(
         planes, pk_b_mont, pk_a_mont, ctx, seed=seed, nonce0=nonce0,
-        batch_block=batch_block, interpret=interpret, datapath=datapath)
+        interpret=interpret, datapath=datapath)
 
 
 def decrypt_decode_stream(c0, c1, s_mont, ctx: CKKSContext, scale,
-                          batch_block: int | None = None,
                           interpret: bool | None = None,
                           datapath: str = "f64"):
     """(B, 2, N) ciphertext stacks -> four (B, n_slots) f32 df slot planes,
@@ -279,8 +276,7 @@ def decrypt_decode_stream(c0, c1, s_mont, ctx: CKKSContext, scale,
     in a single kernel body."""
     interpret = default_interpret() if interpret is None else interpret
     return client_stream.decrypt_decode_stream(
-        c0, c1, s_mont, ctx, scale, batch_block=batch_block,
-        interpret=interpret, datapath=datapath)
+        c0, c1, s_mont, ctx, scale, interpret=interpret, datapath=datapath)
 
 
 # ---------------------------------------------------------------------------
@@ -288,40 +284,21 @@ def decrypt_decode_stream(c0, c1, s_mont, ctx: CKKSContext, scale,
 # ---------------------------------------------------------------------------
 
 
-def _row_padded(f, planes, m, block_rows, interpret):
-    """Run a plane-tuple FFT with the row axis padded to >= 2.
-
-    XLA specializes the (1, N) shape differently (reassociation in the
-    df32 TwoSum/TwoProd tails), so a rows=1 launch drifts in the lo planes
-    relative to the same row inside any rows>=2 batch. The client service
-    requires batch-shape-transparent bits (any bucket/padding/shard must
-    reproduce the direct batched call), so a lone row is duplicated to two
-    and sliced back — making every batch shape, including B=1 and
-    single-row shards, bit-identical per row.
-    """
-    rows = planes[0].shape[0]
-    if rows != 1:
-        return f(planes, m, block_rows=block_rows, interpret=interpret)
-    padded = tuple(jnp.concatenate([p, p]) for p in planes)
-    out = f(padded, m, block_rows=block_rows, interpret=interpret)
-    return tuple(o[:1] for o in out)
-
-
 def special_fft_planes(planes, m: int, block_rows: int = 1,
                        interpret: bool | None = None):
     """Jit-traceable df32 SpecialFFT on a four-plane (rows, n) f32 tuple.
     Nests inside the client's jitted decode core (no host round-trip)."""
     interpret = default_interpret() if interpret is None else interpret
-    return _row_padded(fft_df.special_fft_planes, planes, m, block_rows,
-                       interpret)
+    return fft_df.special_fft_planes(planes, m, block_rows=block_rows,
+                                     interpret=interpret)
 
 
 def special_ifft_planes(planes, m: int, block_rows: int = 1,
                         interpret: bool | None = None):
     """Jit-traceable df32 SpecialIFFT on df planes (encode direction)."""
     interpret = default_interpret() if interpret is None else interpret
-    return _row_padded(fft_df.special_ifft_planes, planes, m, block_rows,
-                       interpret)
+    return fft_df.special_ifft_planes(planes, m, block_rows=block_rows,
+                                      interpret=interpret)
 
 
 def special_fft(z, m: int, block_rows: int = 1, interpret: bool | None = None):

@@ -42,6 +42,13 @@ def _messages(ctx, batch, seed=0):
 # the launch-count guard stays cheap enough for the tier-1 lane.
 
 
+def _fresh(impl):
+    """A new function object around a core impl: make_jaxpr caches traces
+    by function, and another test may already have traced this one (the
+    counter sees only pallas_calls that trace)."""
+    return lambda *args: impl(*args)
+
+
 def test_megakernel_cores_lower_single_pallas_call(pallas_call_counter,
                                                    tiny_mega_client):
     """pipeline='megakernel' traces encode+encrypt and decrypt+decode as
@@ -53,13 +60,17 @@ def test_megakernel_cores_lower_single_pallas_call(pallas_call_counter,
     msgs = _messages(ctx, 3)
     re, im = jnp.asarray(msgs.real), jnp.asarray(msgs.imag)
 
+    # grid (batch blocks, limbs): the whole tiny batch per step, one limb
+    # of public key and ciphertext per step
+    limb_grid = [(1, ctx.params.n_limbs)]
     pallas_call_counter.clear()
-    jax.make_jaxpr(client._encrypt_core_mega_impl)(re, im, jnp.uint32(0))
-    assert pallas_call_counter == [(1,)]       # whole batch per grid step
+    jax.make_jaxpr(_fresh(client._encrypt_core_mega_impl))(
+        re, im, jnp.uint32(0))
+    assert pallas_call_counter == limb_grid
 
     c0 = jnp.zeros((3, 2, ctx.params.n), jnp.uint32)
     pallas_call_counter.clear()
-    jax.make_jaxpr(client._decrypt_core_mega_impl)(
+    jax.make_jaxpr(_fresh(client._decrypt_core_mega_impl))(
         c0, c0, jnp.float64(ctx.params.delta))
     assert pallas_call_counter == [(1,)]
 
@@ -67,12 +78,13 @@ def test_megakernel_cores_lower_single_pallas_call(pallas_call_counter,
     # and it is the megakernel body that lowers
     ops = client.encrypt_operands(msgs)
     pallas_call_counter.clear()
-    jax.make_jaxpr(client._encrypt_core_mega32_impl)(*ops, jnp.uint32(0))
-    assert pallas_call_counter == [(1,)]
+    jax.make_jaxpr(_fresh(client._encrypt_core_mega32_impl))(
+        *ops, jnp.uint32(0))
+    assert pallas_call_counter == limb_grid
     assert pallas_call_counter.by_name() == {"_encode_encrypt_kernel": 1}
 
     pallas_call_counter.clear()
-    jax.make_jaxpr(client._decrypt_core_mega32_impl)(
+    jax.make_jaxpr(_fresh(client._decrypt_core_mega32_impl))(
         c0, c0, jnp.float32(ctx.params.delta))
     assert pallas_call_counter == [(1,)]
     assert pallas_call_counter.by_name() == {"_decrypt_decode_kernel": 1}

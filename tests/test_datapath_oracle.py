@@ -33,6 +33,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.extend.core as jax_core
 import jax.numpy as jnp
 
 from repro.core import dfloat as dfl
@@ -331,9 +332,9 @@ def _iter_eqns(jaxpr):
 
 
 def _subjaxprs(v):
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jax_core.ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, jax_core.Jaxpr):
         yield v
     elif isinstance(v, (tuple, list)):
         for item in v:

@@ -38,8 +38,10 @@ from repro.core.context import PROFILES
 from repro.fhe_client.client import FHEClient
 from repro.fhe_client.service import (ClientService, MeshRequestError,
                                       MeshRouter, wire)
+from repro.fhe_client.service import mesh as mesh_mod
 from repro.fhe_client.service.mesh import (DEFAULT_LANE_ID, ANON_LANE_ID,
-                                           _Chunk, lane_wire_identity)
+                                           MeshError, _Chunk,
+                                           lane_wire_identity)
 
 TINY = PROFILES["tiny"]
 BUCKETS = (1, 2, 4)
@@ -337,3 +339,65 @@ def test_mesh_multi_worker_soak_with_midround_kill():
         assert st["failed_requests"] == 0
         assert st["wire"]["requests"] == 36
         assert st["wire"]["send_bytes"] > 0 and st["wire"]["recv_bytes"] > 0
+
+
+def _fake_host(root, pci, vfio=(), accel=()):
+    for i, (vendor, device) in enumerate(pci):
+        d = root / "pci" / f"0000:00:{i:02x}.0"
+        d.mkdir(parents=True)
+        (d / "vendor").write_text(vendor + "\n")
+        (d / "device").write_text(device + "\n")
+    (root / "dev" / "vfio").mkdir(parents=True)
+    (root / "dev" / "vfio" / "vfio").write_text("")
+    for n in vfio:
+        (root / "dev" / "vfio" / str(n)).write_text("")
+    for n in accel:
+        (root / "dev" / f"accel{n}").write_text("")
+    return str(root / "pci"), str(root / "dev")
+
+
+V5E = ("0x1ae0", "0x0063")
+GVNIC = ("0x1ae0", "0x0042")
+
+
+@pytest.mark.parametrize("pci,vfio,accel,chips", [
+    ([V5E] * 4, range(4), (), 4),
+    ([V5E] * 4, (0,), (), 1),          # a host that passes one chip of 4
+    ([V5E] * 4, (), range(4), 4),
+    ([GVNIC, ("0x10de", "0x0063")], range(2), (), 0),
+    ([], (), (), 0),
+], ids=["vfio4", "vfio1_of_4", "accel4", "vfio_not_tpu", "none"])
+def test_host_tpu_chips(tmp_path, pci, vfio, accel, chips):
+    """Chips are the TPU device files the host passes, counted only where
+    the PCI bus shows TPU chips: a Google device that is not a TPU (a
+    gVNIC) or another vendor's device behind VFIO is no chip."""
+    assert mesh_mod.host_tpu_chips(
+        *_fake_host(tmp_path, pci, vfio, accel)) == chips
+
+
+@pytest.mark.parametrize("platforms,pinned", [
+    (None, True), ("", True), ("tpu", True), ("tpu,cpu", True),
+    ("cpu", False),
+], ids=["unset", "empty", "tpu", "tpu_cpu", "cpu"])
+def test_worker_envs_follow_the_callers_platform(platforms, pinned):
+    """On a host with TPU chips the workers are pinned one chip each only
+    where the inherited JAX_PLATFORMS lets JAX take the TPU; a CPU mesh
+    (JAX_PLATFORMS=cpu) keeps the inherited environment unchanged."""
+    env = {"PATH": "/bin"}
+    if platforms is not None:
+        env["JAX_PLATFORMS"] = platforms
+    envs = mesh_mod.worker_envs(env, 2, chips=4)
+    if pinned:
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1"]
+        assert {e["JAX_PLATFORMS"] for e in envs} == {"tpu,cpu"}
+        assert envs[0]["TPU_PROCESS_PORT"] != envs[1]["TPU_PROCESS_PORT"]
+    else:
+        assert envs == [env, env]
+    assert mesh_mod.worker_envs(env, 2, chips=0) == [env, env]
+
+
+def test_worker_envs_refuse_more_workers_than_chips():
+    with pytest.raises(MeshError, match="need one TPU chip each"):
+        mesh_mod.worker_envs({}, 5, chips=4)
+    assert len(mesh_mod.worker_envs({"JAX_PLATFORMS": "cpu"}, 5,
+                                    chips=4)) == 5

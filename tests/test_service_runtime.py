@@ -19,6 +19,7 @@ from repro.core import scheduler as policy
 from repro.fhe_client.service import (ClientService, FaultInjector,
                                       FaultSpec, QueueFull, RequestFailed)
 from repro.fhe_client.service.batcher import now
+from repro.fhe_client.service.faults import is_stream_fault
 
 
 def _msgs(client, b, seed=0):
@@ -367,6 +368,59 @@ def test_always_on_survives_stream_death(rt_client):
     assert "stream_failed" in svc.events.kinds()
     assert svc.scheduler.alive_streams == [1]
     assert svc.stats()["failed_requests"] == 0
+
+
+def _mosaic_refusal():
+    import jax
+    return jax.errors.JaxRuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel: unsupported shape")
+
+
+@pytest.mark.parametrize("refusal", [
+    lambda: NotImplementedError("Unsupported cast: uint32 -> float32"),
+    _mosaic_refusal,
+], ids=["lowering", "mosaic_compile"])
+def test_compile_refusal_propagates_without_stream_death(rt_client, refusal):
+    """A program the device refuses to lower or compile is not a stream
+    fault: launch_job's error reaches the caller as itself, no stream is
+    marked dead, no job is re-queued, and every request of the round —
+    the one launched before the refusal included — fails with it."""
+    cl = rt_client
+    svc = ClientService(client=cl, buckets=(2,), n_streams=2,
+                        oversubscribe=True)
+    err = refusal()
+
+    def refuse(job):
+        raise err
+
+    svc.scheduler.streams[1].launch = refuse     # stream 0 launches fine
+    rids = [svc.submit_encrypt(m) for m in _msgs(cl, 3, seed=72)]
+    with pytest.raises(type(err)) as exc:
+        svc.flush()
+    assert exc.value is err
+    kinds = svc.events.kinds()
+    assert "requeue" not in kinds and "stream_failed" not in kinds
+    assert svc.scheduler.alive_streams == [0, 1]
+    for rid in rids:
+        with pytest.raises(RequestFailed) as failed:
+            svc.result(rid)
+        assert failed.value.cause is err
+    assert svc.stats()["inflight"] == 0
+
+
+@pytest.mark.parametrize("message,is_fault", [
+    ("INTERNAL: Mosaic failed to compile TPU kernel: bad layout", False),
+    ("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+     "memory in memory space vmem", False),
+    ("INTERNAL: error while running the compiled program: core halted",
+     True),
+    ("UNAVAILABLE: TPU device lost", True),
+], ids=["mosaic", "xla_tpu", "runtime_compiled_program", "device_lost"])
+def test_runtime_error_classification(message, is_fault):
+    """Only the compilers' refusal prefixes make a device runtime error a
+    refusal; any other runtime error is a stream fault and fails over."""
+    import jax
+    assert is_stream_fault(jax.errors.JaxRuntimeError(message)) is is_fault
 
 
 def test_all_streams_dead_fails_requests_loudly(rt_client):
