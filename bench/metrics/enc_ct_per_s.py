@@ -1,0 +1,8 @@
+"""enc_ct_per_s: encryptions whose c0 and c1 reached host memory inside
+the window, over the window's seconds (host clock)."""
+
+
+def read(r):
+    done = [d for d in r.window.requests if d.kind == "enc"
+            and d.t_done is not None and d.t_done < r.window.t1]
+    return len(done) / r.seconds
