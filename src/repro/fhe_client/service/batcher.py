@@ -33,6 +33,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.core.encryptor import CiphertextBatch
+from repro.telemetry import annotation
 
 DEFAULT_BUCKETS = (1, 2, 4, 8, 16)
 
@@ -64,6 +65,10 @@ class EncJob:
     tenant: object = None        # lane key this whole bucket belongs to
     spans: tuple = ()            # telemetry span per real row (Nones ok)
     t_coalesce: float = 0.0      # when this job was coalesced (0 = unset)
+    # the open ``fhe.dispatch.<kind>`` profiler annotation: coalesce ->
+    # launch, closed where the launch is stamped
+    annotation: object = dataclasses.field(default=None, compare=False,
+                                           repr=False)
 
     @property
     def bucket(self) -> int:
@@ -85,6 +90,10 @@ class DecJob:
     tenant: object = None        # lane key this whole bucket belongs to
     spans: tuple = ()            # telemetry span per real row (Nones ok)
     t_coalesce: float = 0.0      # when this job was coalesced (0 = unset)
+    # the open ``fhe.dispatch.<kind>`` profiler annotation: coalesce ->
+    # launch, closed where the launch is stamped
+    annotation: object = dataclasses.field(default=None, compare=False,
+                                           repr=False)
 
     @property
     def bucket(self) -> int:
@@ -178,7 +187,8 @@ class CoalescingBatcher:
                 rids=tuple(r.rid for r in reqs),
                 t_submits=tuple(r.t_submit for r in reqs),
                 tenant=tenant,
-                spans=tuple(r.span for r in reqs), t_coalesce=now()))
+                spans=tuple(r.span for r in reqs),
+                annotation=annotation("dispatch", "enc"), t_coalesce=now()))
             used += b
         return jobs, used
 
@@ -206,7 +216,8 @@ class CoalescingBatcher:
                 rids=tuple(r.rid for r in reqs),
                 t_submits=tuple(r.t_submit for r in reqs),
                 tenant=tenant,
-                spans=tuple(r.span for r in reqs), t_coalesce=now()))
+                spans=tuple(r.span for r in reqs),
+                annotation=annotation("dispatch", "dec"), t_coalesce=now()))
         return jobs
 
 

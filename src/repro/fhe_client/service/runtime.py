@@ -23,6 +23,12 @@ overlaps device execution, which is what keeps the streams busy under a
 sustained open-loop request arrival (the paper's premise: the client must
 keep up with a stream, not a benchmark's pre-formed batch).
 
+Under ``jax.profiler`` each job's host stages show on these threads as
+annotations on the device trace's clock: ``fhe.coalesce`` and
+``fhe.dispatch.<kind>`` (coalesce -> launch) on the dispatch thread,
+``fhe.execute.<kind>`` (the block on the device) and ``fhe.demux.<kind>``
+(materialize -> the result rows on the host) on the completion thread.
+
 Failure containment: a JetThread never dies silently. Any unexpected
 exception is recorded (``crashed``), logged as a ``loop_error`` event,
 every queued/in-flight request is failed with ``RequestFailed``, and the

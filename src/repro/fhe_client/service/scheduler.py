@@ -41,6 +41,7 @@ from repro.fhe_client.service.faults import (
     AllStreamsFailed, EventLog, is_stream_fault,
 )
 from repro.kernels import ops as kops
+from repro.telemetry import close_annotation
 
 
 @dataclasses.dataclass(frozen=True)
@@ -273,6 +274,7 @@ class DualStreamScheduler:
                 rec = DispatchRecord(
                     round=self._round, stream=stream, kind=kind, mode=mode,
                     bucket=job.bucket, rids=job.rids, t_launch=now())
+                close_annotation(job.annotation)
                 self.log.append(rec)
                 if self.telemetry is not None:
                     self.telemetry.on_launch(rec, job)

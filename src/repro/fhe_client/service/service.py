@@ -62,7 +62,9 @@ from repro.fhe_client.service.faults import (AllStreamsFailed, EventLog,
 from repro.fhe_client.service.scheduler import DualStreamScheduler
 from repro.fhe_client.tenancy import (KeyContextRegistry,
                                       params_fingerprint)
-from repro.telemetry import ServiceTelemetry, jit_cache_entries
+from repro.telemetry import (COALESCE_ANNOTATION, ServiceTelemetry,
+                             annotation, close_annotation,
+                             jit_cache_entries)
 
 
 def lane_fingerprint(lane) -> str:
@@ -467,38 +469,39 @@ class ClientService:
         included (the flush/drain path). Lanes drain in round-robin order;
         each lane's jobs carry its own nonce lease from its own client.
         Returns (enc_jobs, dec_jobs)."""
-        enc_jobs, dec_jobs = [], []
-        for key in self._rr_queue_keys():
-            lane, kind = key
-            fire, partial = (True, True) if decision is None \
-                else decision.get(key, (False, False))
-            if not fire or not self._queues[key]:
-                continue
-            fp = self._lane_fp(lane)
-            if kind == "enc":
-                p = lane[1] if lane is not None else self.client.ctx.params
-                jobs, n_nonces = self.batcher.coalesce_enc(
-                    self._queues[key], nonce0=0, n_slots=p.n_slots,
-                    allow_partial=partial, tenant=lane)
-                if n_nonces:
-                    base = self._take_nonces(lane, n_nonces)
-                    t_lease = now()
-                    jobs = [dataclasses.replace(j, nonce0=base + j.nonce0)
-                            for j in jobs]
-                    for j in jobs:
-                        self.telemetry.on_lease(j, t_lease)
-                enc_jobs += jobs
-            else:
-                jobs = self.batcher.coalesce_dec(
-                    self._queues[key], allow_partial=partial, tenant=lane)
-                dec_jobs += jobs
-            depth = len(self._queues[key])
-            for j in jobs:
-                self.telemetry.on_coalesce(j, fp, depth)
-        self._inflight += sum(j.n_real for j in enc_jobs + dec_jobs)
-        if enc_jobs or dec_jobs:
-            self._cond.notify_all()   # queue space freed: wake submitters
-        return enc_jobs, dec_jobs
+        with jax.profiler.TraceAnnotation(COALESCE_ANNOTATION):
+            enc_jobs, dec_jobs = [], []
+            for key in self._rr_queue_keys():
+                lane, kind = key
+                fire, partial = (True, True) if decision is None \
+                    else decision.get(key, (False, False))
+                if not fire or not self._queues[key]:
+                    continue
+                fp = self._lane_fp(lane)
+                if kind == "enc":
+                    p = lane[1] if lane is not None else self.client.ctx.params
+                    jobs, n_nonces = self.batcher.coalesce_enc(
+                        self._queues[key], nonce0=0, n_slots=p.n_slots,
+                        allow_partial=partial, tenant=lane)
+                    if n_nonces:
+                        base = self._take_nonces(lane, n_nonces)
+                        t_lease = now()
+                        jobs = [dataclasses.replace(j, nonce0=base + j.nonce0)
+                                for j in jobs]
+                        for j in jobs:
+                            self.telemetry.on_lease(j, t_lease)
+                    enc_jobs += jobs
+                else:
+                    jobs = self.batcher.coalesce_dec(
+                        self._queues[key], allow_partial=partial, tenant=lane)
+                    dec_jobs += jobs
+                depth = len(self._queues[key])
+                for j in jobs:
+                    self.telemetry.on_coalesce(j, fp, depth)
+            self._inflight += sum(j.n_real for j in enc_jobs + dec_jobs)
+            if enc_jobs or dec_jobs:
+                self._cond.notify_all()   # queue space freed: wake submitters
+            return enc_jobs, dec_jobs
 
     # --- completion / failure handling --------------------------------------
 
@@ -510,19 +513,21 @@ class ClientService:
             if s not in alive and self.monitor.hosts[s].alive:
                 self.monitor.mark_failed(s)
 
-    def _store(self, job, rows, t_done):
-        """Demux one completed job's real rows into per-request results."""
+    def _store(self, job, rows, t_demux):
+        """Store one completed job's real rows as per-request results;
+        ``t_demux`` is when the rows came to exist on the host."""
         with self._cond:
             for rid, t_sub, row in zip(job.rids, job.t_submits, rows):
                 self._results[rid] = row
-                self._latencies[rid] = t_done - t_sub
+                self._latencies[rid] = t_demux - t_sub
             self._inflight -= job.n_real
             self._completed_total += job.n_real
             self._cond.notify_all()
-        self.telemetry.on_complete(job, self._lane_fp(job.tenant), t_done)
+        self.telemetry.on_complete(job, self._lane_fp(job.tenant), t_demux)
 
     def _fail(self, job, attempt, cause):
         """Exhausted retries (or no streams left): fail the job's rids."""
+        close_annotation(job.annotation)      # a job that never launched
         self.events.record("request_failed", rids=job.rids, attempt=attempt,
                            detail=repr(cause))
         with self._cond:
@@ -555,8 +560,9 @@ class ClientService:
         while True:
             t0 = now()
             try:
-                self.scheduler.check_materialize(rec, job)
-                jax.block_until_ready(out)
+                with annotation("execute", job.kind):
+                    self.scheduler.check_materialize(rec, job)
+                    jax.block_until_ready(out)
             except Exception as e:  # noqa: BLE001 — classified below
                 if not is_stream_fault(e):
                     self._fail(job, attempt, e)
@@ -584,9 +590,20 @@ class ClientService:
                         raise
                 continue
             break
-        dt = now() - t0
-        t_done = now()
-        self.telemetry.on_materialize(rec, job, t_done)
+        t_materialize = now()
+        # materialize -> demux: the device output is ready; the host
+        # readback and finish make the result rows
+        with annotation("demux", job.kind):
+            self._observe_job(rec, job, attempt, t_materialize,
+                              t_materialize - t0)
+            rows = self._demux(job, out)
+            t_demux = now()
+        self._store(job, rows, t_demux)
+
+    def _observe_job(self, rec, job, attempt, t_materialize, dt):
+        """A job's output materialized after ``dt`` s of blocking: stamp
+        it, feed the fleet monitor, and isolate a straggling stream."""
+        self.telemetry.on_materialize(rec, job, t_materialize)
         with self._sched_lock:
             self.monitor.heartbeat(rec.stream)
             self.monitor.report_step_time(rec.stream, dt)
@@ -609,7 +626,6 @@ class ClientService:
             self.events.record("retry_ok", stream=rec.stream,
                                round=rec.round, rids=job.rids,
                                attempt=attempt)
-        self._store(job, self._demux(job, out), t_done)
 
     # --- execution (closed-loop mode) ---------------------------------------
 
@@ -763,8 +779,10 @@ class ClientService:
                     or rid in self._failures)
 
     def latency(self, rid: int) -> float:
-        """Submit-to-materialize latency (s) of a completed request.
-        Latency entries and the dispatch log accumulate until
+        """Submit-to-demux latency (s) of a completed request: it ends
+        when the job's result rows exist on the host, at the same stamp
+        as the ``total`` stage of ``fhe_stage_seconds``, and does not
+        depend on when a caller retrieves the row. Latency entries and the dispatch log accumulate until
         ``reset_telemetry`` — long-running servers should reset between
         reporting windows."""
         return self._latencies[rid]

@@ -11,8 +11,9 @@ the one observability surface the serving stack records into:
     text exposition (bounded label cardinality, fingerprint-only labels);
   * ``telemetry.tracing``  — per-request span contexts stamped at every
     lifecycle stage (submit -> admit -> coalesce -> lease -> launch ->
-    materialize -> demux -> result), a bounded completed-span ring, and
-    Chrome trace-event JSON export (one track per stream + queue tracks);
+    materialize -> demux -> result), a bounded completed-span ring,
+    Chrome trace-event JSON export (one track per stream + queue tracks),
+    and the ``fhe.*`` profiler annotations of the job-level host stages;
   * ``telemetry.probe``    — the jit-cache re-lowering odometer shared by
     the workload-matrix bench, the tests and the metrics snapshot;
   * ``ServiceTelemetry``   — the per-service bundle of all three, with
@@ -33,13 +34,16 @@ from repro.telemetry.metrics import (Counter, DEFAULT_TIME_BUCKETS, Gauge,
                                      Histogram, MetricsRegistry,
                                      OVERFLOW_LABEL)
 from repro.telemetry.probe import CLIENT_CORE_ATTRS, jit_cache_entries
-from repro.telemetry.tracing import (STAGES, Span, Tracer,
+from repro.telemetry.tracing import (ANNOTATIONS, COALESCE_ANNOTATION,
+                                     STAGES, Span, Tracer, annotation,
+                                     close_annotation,
                                      spans_to_chrome_trace,
                                      validate_chrome_trace)
 
 # interval stages the per-stage latency histogram records, as
-# (name, from-stamp, to-stamp); "total" is the submit->materialized
-# latency ``ClientService.latency`` also reports
+# (name, from-stamp, to-stamp); "total" is the submit -> demux latency
+# ``ClientService.latency`` also reports: both end when the job's result
+# rows exist on the host, before any caller retrieves them
 STAGE_INTERVALS = (
     ("queue_wait", "submit", "coalesce"),
     ("dispatch", "coalesce", "launch"),
@@ -147,10 +151,9 @@ class ServiceTelemetry:
                         stream=rec.stream, round=rec.round,
                         attempt=rec.attempt)
         if job.t_coalesce:
-            dt = rec.t_launch - job.t_coalesce
-            for _ in range(job.n_real):
-                self.stage_seconds.observe(dt, stage="dispatch",
-                                           kind=rec.kind)
+            self.stage_seconds.observe(rec.t_launch - job.t_coalesce,
+                                       job.n_real, stage="dispatch",
+                                       kind=rec.kind)
 
     def on_round(self, mode) -> None:
         if not self.enabled:
@@ -164,17 +167,17 @@ class ServiceTelemetry:
             return
         Tracer.mark_all(job.spans, "materialize", t, stream=rec.stream)
         if rec.t_launch:
-            dt = t - rec.t_launch
-            for _ in range(job.n_real):
-                self.stage_seconds.observe(dt, stage="execute",
-                                           kind=rec.kind)
+            self.stage_seconds.observe(t - rec.t_launch, job.n_real,
+                                       stage="execute", kind=rec.kind)
 
-    def on_complete(self, job, lane: str, t_done: float) -> None:
+    def on_complete(self, job, lane: str, t_demux: float) -> None:
+        """A job's result rows exist on the host (``t_demux``, taken when
+        ``_demux`` returns): stamp ``demux`` and observe ``total``."""
         if not self.enabled:
             return
-        Tracer.mark_all(job.spans, "demux", t_done)
+        Tracer.mark_all(job.spans, "demux", t_demux)
         for t_sub in job.t_submits:
-            self.stage_seconds.observe(t_done - t_sub, stage="total",
+            self.stage_seconds.observe(t_demux - t_sub, stage="total",
                                        kind=job.kind)
         self.completed.inc(job.n_real, lane=lane, kind=job.kind)
         for span in job.spans:
@@ -370,9 +373,10 @@ class MeshTelemetry:
 
 
 __all__ = [
-    "CLIENT_CORE_ATTRS", "Counter", "DEFAULT_TIME_BUCKETS", "Gauge",
+    "ANNOTATIONS", "CLIENT_CORE_ATTRS", "COALESCE_ANNOTATION", "Counter", "DEFAULT_TIME_BUCKETS", "Gauge",
     "Histogram", "MeshTelemetry", "MetricsRegistry", "OVERFLOW_LABEL",
     "STAGES", "STAGE_INTERVALS", "STAGE_NAMES", "ServiceTelemetry",
-    "Span", "Tracer", "jit_cache_entries", "metrics", "probe",
+    "Span", "Tracer", "annotation", "close_annotation",
+    "jit_cache_entries", "metrics", "probe",
     "spans_to_chrome_trace", "tracing", "validate_chrome_trace",
 ]

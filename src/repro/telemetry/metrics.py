@@ -177,14 +177,15 @@ class Histogram(_Metric):
     def _new_cell(self):
         return _HistCell(len(self.bounds))
 
-    def observe(self, value: float, **labels) -> None:
+    def observe(self, value: float, count: int = 1, **labels) -> None:
+        """Record ``value`` ``count`` times (one lock for a job's rows)."""
         key = self._key(labels)
         i = bisect.bisect_left(self.bounds, value)
         with self._lock:
             cell = self._cell(key)
-            cell.counts[i] += 1
-            cell.total += 1
-            cell.sum += value
+            cell.counts[i] += count
+            cell.total += count
+            cell.sum += value * count
 
     def _freeze(self, cell):
         return {"counts": list(cell.counts), "total": cell.total,

@@ -13,6 +13,15 @@ as Chrome trace-event JSON — loadable in ``chrome://tracing`` / Perfetto —
 with one track per execution stream plus per-kind queue tracks, so "where
 does a request's time go" is a picture, not a guess.
 
+The job-level host stages also run inside ``jax.profiler``
+annotations (``fhe.coalesce``, ``fhe.dispatch.<kind>``,
+``fhe.execute.<kind>``, ``fhe.demux.<kind>``), recorded on the
+profiler's clock, the same timeline as the device's ops. Each opens and
+closes at the statements where its stage's two stamps are taken, so a
+profile and the span stamps measure the same interval. Their names are
+built once here (``ANNOTATIONS``); with no profiler running an
+annotation costs one activity check.
+
 Cost model: a ``Tracer`` with ``enabled=False`` (or a request outside the
 sample) returns ``None`` from ``begin`` and every downstream ``mark_all``
 skips Nones — the disabled path is one attribute check per stage, no
@@ -28,11 +37,33 @@ import threading
 import time
 from collections import OrderedDict
 
+from jax.profiler import TraceAnnotation
+
 # canonical stage order (span validity tests check stamps stay sorted)
 STAGES = ("submit", "admit", "coalesce", "lease", "launch",
           "materialize", "demux", "result", "failed")
 
 _STAGE_RANK = {s: i for i, s in enumerate(STAGES)}
+
+# profiler annotation names, one per (stage, kind), built once: the hot
+# path formats no string and passes no annotation kwargs
+COALESCE_ANNOTATION = "fhe.coalesce"
+ANNOTATIONS = {(stage, kind): f"fhe.{stage}.{kind}"
+               for stage in ("dispatch", "execute", "demux")
+               for kind in ("enc", "dec")}
+
+
+def annotation(stage: str, kind: str) -> TraceAnnotation:
+    """An open profiler annotation for one job's host stage; it ends at
+    ``__exit__`` (a ``with`` block's end, or ``close_annotation``)."""
+    return TraceAnnotation(ANNOTATIONS[stage, kind])
+
+
+def close_annotation(ann) -> None:
+    """End an annotation opened outside a ``with`` block (None skips;
+    closing twice records one event)."""
+    if ann is not None:
+        ann.__exit__(None, None, None)
 
 
 class Span:

@@ -1,7 +1,7 @@
 """Unified telemetry layer: labeled metrics, request-lifecycle spans,
 Chrome-trace export, and the service wiring.
 
-Three contracts under test:
+Four contracts under test:
 
   * **Reconciliation.** Every telemetry view of one window agrees:
     the ``fhe_jobs_total`` counter vs the scheduler dispatch log, the
@@ -13,9 +13,15 @@ Three contracts under test:
     memory flat and the cardinality overflow folds into one series.
   * **Near-zero cost when off.** A disabled scope allocates no spans,
     creates no series and adds no kernel lowerings (pallas pin).
+  * **Stamps mean what they say.** ``demux`` is taken after the host
+    finish, and each job's host stages are profiler annotations on the
+    service's own threads, one per dispatch-log record.
 """
 
+import glob
 import json
+import os
+import time
 
 import numpy as np
 import pytest
@@ -102,6 +108,23 @@ def test_histogram_quantiles_and_exposition():
     snap = reg.snapshot()["lat"]
     assert snap["bounds"] == [0.001, 0.01, 0.1, 1.0]
     assert sum(snap["series"][0]["counts"]) == 100
+
+
+def test_histogram_observe_count_is_repeated_observes():
+    """One ``observe(v, n)`` per job reads as ``n`` observes of ``v``:
+    bucket counts, total, sum and the exposition."""
+    regs = [MetricsRegistry(), MetricsRegistry()]
+    hs = [r.histogram("lat", labelnames=("stage",),
+                      buckets=(0.001, 0.01, 0.1)) for r in regs]
+    for v, n in ((0.0005, 16), (0.05, 3), (2.0, 5)):
+        for _ in range(n):
+            hs[0].observe(v, stage="execute")
+        hs[1].observe(v, n, stage="execute")
+    (a,), (b,) = hs[0].series().values(), hs[1].series().values()
+    assert a["counts"] == b["counts"] and a["total"] == b["total"]
+    assert a["sum"] == pytest.approx(b["sum"])
+    assert regs[0].exposition() == regs[1].exposition()
+    assert hs[1].summary(stage="execute")["count"] == 24
 
 
 def test_default_time_buckets_cover_us_to_minutes():
@@ -269,6 +292,84 @@ def test_span_tree_replays_dispatch_log(tele_client):
     for kind in set(svc.events.kinds()):
         assert svc.telemetry.events.value(kind=kind) == \
             len(svc.events.replay(kind))
+
+
+def test_demux_stamp_closes_the_host_finish(tele_client):
+    """``demux`` is stamped when ``_demux`` returns, before any caller
+    retrieves a row: a 20 ms host finish shows between materialize and
+    demux, in the Chrome trace's demux slice, and in ``latency`` and the
+    ``total`` stage, which end at the same stamp."""
+    svc = ClientService(client=tele_client, buckets=(2,), max_wait_s=0.05)
+    demux = svc._demux
+
+    def slow_demux(job, out):
+        time.sleep(0.02)
+        return demux(job, out)
+
+    svc._demux = slow_demux
+    rids = _run_mix(svc, tele_client)
+    spans = svc.telemetry.tracer.spans()
+    assert {s.rid for s in spans} == set(rids)
+    for s in spans:
+        assert s.t("demux") - s.t("materialize") >= 0.02, s.marks
+        assert s.t("demux") <= s.t("result")
+        assert svc.latency(s.rid) == pytest.approx(
+            s.t("demux") - s.t("submit"))
+    total = sum(svc.telemetry.stage_seconds.summary(
+        stage="total", kind=k)["sum"] for k in ("enc", "dec"))
+    assert total == pytest.approx(sum(svc.latency(r) for r in rids))
+    slices = [e for e in svc.telemetry.chrome_trace()["traceEvents"]
+              if e["name"] == "demux"]
+    assert len(slices) == len(rids)
+    assert all(e["dur"] >= 2e4 for e in slices)     # microseconds
+
+
+def _host_lines(trace_dir):
+    """[(line index, event name)] of every event on the profile's host
+    plane."""
+    from jax.profiler import ProfileData
+    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(paths) == 1, paths
+    host = [p for p in ProfileData.from_file(paths[0]).planes
+            if p.name == "/host:CPU"]
+    assert len(host) == 1
+    return [(i, e.name) for i, ln in enumerate(host[0].lines)
+            for e in ln.events]
+
+
+def test_service_stages_on_the_profiler_clock(tele_client, tmp_path):
+    """The always-on service's job-level host stages are profiler
+    annotations: one ``fhe.dispatch.<kind>`` and one
+    ``fhe.demux.<kind>`` per dispatch-log record, each on a service
+    thread's line, never the caller's."""
+    import jax
+    svc = ClientService(client=tele_client, buckets=(2,), max_wait_s=0.05)
+    _run_mix(svc, tele_client)             # every shape compiled first
+    svc.reset_telemetry()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    svc.start()
+    try:
+        with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+            with jax.profiler.TraceAnnotation("test.caller"):
+                _run_mix(svc, tele_client, seed=1)
+    finally:
+        svc.stop()
+    events = _host_lines(str(tmp_path))
+    caller = {i for i, name in events if name == "test.caller"}
+    assert len(caller) == 1
+    log = svc.dispatch_log
+    assert {r.kind for r in log} == {"enc", "dec"}
+    for kind in ("enc", "dec"):
+        n_jobs = sum(r.kind == kind for r in log)
+        for stage in ("dispatch", "execute", "demux"):
+            lines = [i for i, name in events
+                     if name == f"fhe.{stage}.{kind}"]
+            assert len(lines) == n_jobs, (stage, kind, events)
+            assert caller.isdisjoint(lines), (stage, kind)
+    coalesce = [i for i, name in events if name == "fhe.coalesce"]
+    assert coalesce and caller.isdisjoint(coalesce)
 
 
 def test_jobs_counter_agrees_with_dispatch_log(tele_client):
