@@ -44,6 +44,27 @@ from repro.kernels import ops as kops
 from repro.telemetry import close_annotation
 
 
+@jax.jit
+def _unstack(x):
+    """One program: a bucket's (B, ...) array -> B arrays of one row."""
+    return tuple(x[i] for i in range(x.shape[0]))
+
+
+def bucket_rows(out, n_real: int) -> list:
+    """The ``n_real`` real rows of a launched encrypt output, each a device
+    array of its own, made by one program per device that holds a shard,
+    with each row's copy to the host started: the copies run as soon as
+    the kernel ends, and each row comes to the host as a buffer of its
+    own (a whole-bucket transfer measured about 4x slower on a TPU
+    v5e)."""
+    shards = sorted(out.addressable_shards,
+                    key=lambda s: s.index[0].start or 0)
+    rows = [r for s in shards for r in _unstack(s.data)][:n_real]
+    for r in rows:
+        r.copy_to_host_async()
+    return rows
+
+
 @dataclasses.dataclass(frozen=True)
 class DispatchRecord:
     """One job launch: which stream ran what, under which top-level mode.
@@ -153,8 +174,9 @@ class StreamExecutor:
                     f"lane's parameter set has n_slots="
                     f"{client.ctx.params.n_slots}")
             ops = client.encrypt_operands(job.messages)
-            return enc(*[self._place(o) for o in ops],
-                       jnp.uint32(job.nonce0))
+            c0, c1 = enc(*[self._place(o) for o in ops],
+                         jnp.uint32(job.nonce0))
+            return bucket_rows(c0, job.n_real), bucket_rows(c1, job.n_real)
         assert isinstance(job, DecJob)
         return dec(self._place(job.cts.c0),
                    self._place(job.cts.c1),

@@ -539,15 +539,23 @@ class ClientService:
         self.telemetry.on_fail(job, self._lane_fp(job.tenant), now())
 
     def _demux(self, job, out):
-        """Materialized job output -> real result rows, under the job's
-        OWN lane client (a tenant's results decode with its parameter set
-        and scales, never the default client's)."""
+        """Materialized job output -> real result rows in host memory,
+        under the job's OWN lane client (a tenant's results decode with
+        its parameter set and scales, never the default client's).
+
+        An encrypt job's output is its real rows of ``c0`` and ``c1``,
+        one device array each, whose copies to the host started at
+        launch (``scheduler.bucket_rows``): each row becomes a host
+        ``np.ndarray`` over a buffer of its own, so a ciphertext the
+        caller keeps never pins the rest of the job."""
         client = self._client_for(job.tenant)
         if isinstance(job, EncJob):
-            c0, c1 = out
+            c0, c1 = ([np.asarray(r) for r in rows] for rows in out)
+            self.telemetry.on_readback("enc", c0 + c1)
             p = client.ctx.params
-            return [Ciphertext(c0=c0[i], c1=c1[i], n_limbs=p.n_limbs,
-                               scale=p.delta) for i in range(job.n_real)]
+            return [Ciphertext(c0=a, c1=b, n_limbs=p.n_limbs, scale=p.delta)
+                    for a, b in zip(c0, c1)]
+        self.telemetry.on_readback("dec", out)
         msgs = client.decrypt_results(out, job.scales)
         return [msgs[i] for i in range(job.n_real)]
 
@@ -824,10 +832,9 @@ class ClientService:
         self.flush()
         rows = [self.result(r) for r in rids]
         import jax.numpy as jnp
-        # rows may be committed to different stream devices; gather on host
         return CiphertextBatch(
-            c0=jnp.asarray(np.stack([np.asarray(r.c0) for r in rows])),
-            c1=jnp.asarray(np.stack([np.asarray(r.c1) for r in rows])),
+            c0=jnp.asarray(np.stack([r.c0 for r in rows])),
+            c1=jnp.asarray(np.stack([r.c1 for r in rows])),
             n_limbs=rows[0].n_limbs, scale=rows[0].scale)
 
     def decrypt_many(self, cts) -> np.ndarray:

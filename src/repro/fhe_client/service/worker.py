@@ -138,10 +138,10 @@ class MeshWorker:
             self.svc.flush()
             rows = [self.svc.result(r) for r in rids]
             from repro.core.encryptor import CiphertextBatch
-            import jax.numpy as jnp
+            # the service's rows are host arrays: stack them for the wire
             batch = CiphertextBatch(
-                c0=jnp.asarray(np.stack([np.asarray(r.c0) for r in rows])),
-                c1=jnp.asarray(np.stack([np.asarray(r.c1) for r in rows])),
+                c0=np.stack([r.c0 for r in rows]),
+                c1=np.stack([r.c1 for r in rows]),
                 n_limbs=rows[0].n_limbs, scale=rows[0].scale)
             reply = wire.serialize_ciphertext_batch(batch)
         elif kind in (wire.KIND_CT_BATCH, wire.KIND_CT_SEEDED):

@@ -99,6 +99,9 @@ class ServiceTelemetry:
         self.stage_seconds = m.histogram(
             "fhe_stage_seconds", "per-stage request latency",
             ("stage", "kind"))
+        self.readback_bytes = m.counter(
+            "fhe_readback_bytes_total",
+            "device -> host bytes each job's demux read", ("kind",))
 
     # -- submission ----------------------------------------------------------
 
@@ -169,6 +172,14 @@ class ServiceTelemetry:
         if rec.t_launch:
             self.stage_seconds.observe(t - rec.t_launch, job.n_real,
                                        stage="execute", kind=rec.kind)
+
+    def on_readback(self, kind: str, parts) -> None:
+        """A job's demux read ``parts`` from the device to the host: an
+        encrypt job's real rows of ``c0`` and ``c1``, a decrypt job's
+        whole output (the parts ``decrypt_results`` reads)."""
+        if not self.enabled:
+            return
+        self.readback_bytes.inc(sum(p.nbytes for p in parts), kind=kind)
 
     def on_complete(self, job, lane: str, t_demux: float) -> None:
         """A job's result rows exist on the host (``t_demux``, taken when

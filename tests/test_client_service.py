@@ -34,6 +34,14 @@ def _msgs(client, b, seed=0):
             + 1j * rng.standard_normal((b, n))) * 0.5
 
 
+def _backing_nbytes(a):
+    """Bytes of the buffer a host array keeps alive: its own, or that of
+    the array or buffer at the end of its ``base`` chain."""
+    while isinstance(a.base, np.ndarray):
+        a = a.base
+    return a.nbytes if a.base is None else memoryview(a.base).nbytes
+
+
 @pytest.fixture(scope="module")
 def svc_client():
     """Module-scoped client backing the service tests. NOT the session
@@ -172,6 +180,86 @@ def test_service_encrypt_bit_identical_any_bucket(svc_client):
     np.testing.assert_array_equal(np.asarray(cts.c1), np.asarray(direct.c1))
     assert [r.bucket for r in svc.dispatch_log] == [2, 2]
     assert [r.kind for r in svc.dispatch_log] == ["enc", "enc"]
+
+
+@pytest.mark.parametrize("streams", ["single", "oversubscribed"])
+@pytest.mark.parametrize("mode", ["flush", "always_on"])
+def test_service_encrypt_rows_own_host_memory(svc_client, mode, streams):
+    """Service encrypt rows are host ``np.ndarray``s, each a contiguous
+    (L, N) uint32 array over a buffer of its own (no row shares memory
+    with another or pins a whole bucket), bit-identical to the direct
+    batch from the same nonce base, the row of a partial bucket
+    included."""
+    cl = svc_client
+    msgs = _msgs(cl, 3, seed=6)
+    base = cl.nonce
+    direct = cl.encode_encrypt_batch(msgs)
+    cl.nonce = base
+    group = dict(n_streams=2, oversubscribe=True) \
+        if streams == "oversubscribed" else dict(n_streams=1)
+    svc = ClientService(client=cl, buckets=(2,), **group)
+    if mode == "always_on":
+        svc.start()
+    try:
+        rids = [svc.submit_encrypt(m) for m in msgs]    # jobs: 2 + 1(pad)
+        assert svc.flush() == 3
+        rows = [svc.result(r) for r in rids]
+    finally:
+        svc.stop()
+    assert [r.bucket for r in svc.dispatch_log] == [2, 2]
+    assert {r.stream for r in svc.dispatch_log} \
+        == ({0, 1} if streams == "oversubscribed" else {0})
+
+    p = cl.ctx.params
+    arrays = []
+    for i, row in enumerate(rows):
+        assert (row.n_limbs, row.scale) == (p.n_limbs, p.delta)
+        for got, ref in ((row.c0, direct.c0[i]), (row.c1, direct.c1[i])):
+            assert isinstance(got, np.ndarray)
+            assert not isinstance(got, jax.Array)
+            assert got.shape == (p.n_limbs, p.n) and got.dtype == np.uint32
+            assert got.flags.c_contiguous
+            assert _backing_nbytes(got) == got.nbytes
+            np.testing.assert_array_equal(got, np.asarray(ref))
+            arrays.append(got)
+    for i, a in enumerate(arrays):
+        for b in arrays[i + 1:]:
+            assert not np.shares_memory(a, b)
+
+
+def test_readback_bytes_counter(svc_client, monkeypatch):
+    """``fhe_readback_bytes_total{kind}`` counts the bytes each job's
+    demux read: the real rows' c0 and c1 for encrypt (a padding row never
+    comes to the host), the parts ``decrypt_results`` reads for decrypt;
+    nothing when telemetry is off."""
+    cl = svc_client
+    read = []
+    decrypt_results = cl.decrypt_results
+
+    def counting(parts, scale):
+        read.append(sum(np.asarray(x).nbytes for x in parts))
+        return decrypt_results(parts, scale)
+
+    monkeypatch.setattr(cl, "decrypt_results", counting)
+    p = cl.ctx.params
+    svc = ClientService(client=cl, buckets=(2,))
+    cts = svc.encrypt_many(_msgs(cl, 3, seed=7))      # 2 + 1(pad) rows
+    launched = sum(r.bucket for r in svc.dispatch_log)
+    assert launched == 4
+    counter = svc.telemetry.readback_bytes
+    assert counter.value(kind="enc") == 3 * 2 * p.n_limbs * p.n * 4
+    assert counter.value(kind="dec") == 0
+
+    svc.decrypt_many(cts.truncated(2))                 # 2 + 1(pad) rows
+    assert len(read) == 2 and counter.value(kind="dec") == sum(read)
+    # the df32 decrypt core returns four f32 slot planes per row
+    assert sum(read) == launched * 4 * p.n_slots * 4
+
+    off = ClientService(client=cl, buckets=(2,), telemetry=False)
+    off.decrypt_many(off.encrypt_many(_msgs(cl, 3, seed=8)).truncated(2))
+    assert off.telemetry.readback_bytes.n_series() == 0
+    assert off.telemetry_snapshot()["metrics"] \
+        ["fhe_readback_bytes_total"]["series"] == []
 
 
 def test_service_decrypt_bit_identical(svc_client):
@@ -332,11 +420,13 @@ assert concurrent, f"no round used both streams: {svc.dispatch_log}"
 modes = svc.stats()["modes"]
 assert "2xENC" in modes and "2xDEC" in modes, modes
 
-# encrypt results come back committed to different stream devices; feeding
-# them straight back for decryption must host-gather, not cross-device-crash
+# encrypt results from both stream devices come back as host rows of their
+# own; fed straight back for decryption they upload to either stream
 rids = [svc.submit_encrypt(m) for m in msgs]
 svc.flush()
 rows = [svc.result(r) for r in rids]
+assert all(type(a) is np.ndarray and memoryview(a.base).nbytes == a.nbytes
+           for row in rows for a in (row.c0, row.c1))
 drids = [svc.submit_decrypt(row) for row in rows]
 svc.flush()
 out = np.stack([svc.result(r) for r in drids])
@@ -376,6 +466,22 @@ assert (np.asarray(cts.c0) == np.asarray(direct.c0)).all()
 assert (np.asarray(cts.c1) == np.asarray(direct.c1)).all()
 got = svc.decrypt_many(direct.truncated(2))
 assert (got == ref_dec).all()
+
+# each row of the sharded bucket comes to the host over a buffer of its own
+base = cl.nonce
+rids = [svc.submit_encrypt(m) for m in msgs[:3]]          # partial bucket
+svc.flush()
+rows = [svc.result(r) for r in rids]
+cl.nonce = base
+direct = cl.encode_encrypt_batch(msgs[:3])
+arrays = [a for row in rows for a in (row.c0, row.c1)]
+assert all(type(a) is np.ndarray and memoryview(a.base).nbytes == a.nbytes
+           for a in arrays)
+assert not any(np.shares_memory(a, b)
+               for i, a in enumerate(arrays) for b in arrays[i + 1:])
+for i, row in enumerate(rows):
+    assert (row.c0 == np.asarray(direct.c0)[i]).all()
+    assert (row.c1 == np.asarray(direct.c1)[i]).all()
 print("SHARDED-STREAM-OK")
 """
 
